@@ -194,6 +194,9 @@ def main() -> None:
         from benchmarks import regress
         sys.exit(regress.main([]))
 
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
+
     def finish(mode: str, status: int, rows, errors):
         import jax
         run_id = args.run_id or f"{mode}-jax{jax.__version__}"

@@ -1,28 +1,23 @@
-"""Version-adaptive shims over every JAX API this repo uses that drifted
-between 0.4.x and 0.5+/0.6+.
+"""One import point for the JAX APIs whose spelling has drifted.
 
-POLICY: never call a drifting JAX API directly from repo code — route it
-through here.  The APIs below moved, appeared, or changed shape across the
-JAX releases we support (floor: 0.4.37, see requirements.txt):
+POLICY: repo code reaches these families through this module, never
+directly, so the next JAX bump is a one-file change.  The repo targets
+JAX 0.9 (see requirements.txt); every name below binds straight to the
+0.9 API:
 
-* ``jax.tree.flatten_with_path`` — only ``jax.tree_util``'s spelling exists
-  on 0.4.x; the ``jax.tree`` alias landed later.
-* ``jax.sharding.AxisType`` + ``Mesh(..., axis_types=...)`` — absent on
-  0.4.x; newer JAX defaults them anyway, so :func:`make_mesh` accepts and
-  drops the kwarg where unsupported.
-* ``jax.shard_map`` — top-level export is 0.7+; before that it lives in
-  ``jax.experimental.shard_map``.
-* ``compiled.cost_analysis()`` — a one-element *list* of dicts on 0.4.x, a
-  plain dict on newer releases; :func:`cost_analysis_dict` normalizes.
+* the pytree family — ``jax.tree.*`` (``keystr`` from ``jax.tree_util``,
+  which has no ``jax.tree`` spelling);
+* ``make_mesh`` — ``jax.make_mesh`` with ``axis_types=True`` meaning
+  ``(AxisType.Auto,) * ndim``;
+* ``shard_map`` — the top-level ``jax.shard_map`` (``check_vma``);
+* ``cost_analysis_dict`` — ``compiled.cost_analysis()`` as a plain dict.
 
 Anything stable (``jax.jit``, ``jax.numpy``, ``NamedSharding``,
-``PartitionSpec``) is intentionally NOT wrapped — the shim covers drift,
-not the whole API.  New code that needs one of the wrapped families must
-import it from here so the next JAX bump is a one-file change.
+``PartitionSpec``) is intentionally NOT wrapped.
 """
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, Optional, Sequence, Tuple
+from typing import Any, Dict, Sequence, Tuple
 
 import jax
 import jax.tree_util as jtu
@@ -46,19 +41,16 @@ __all__ = [
 
 
 # ---------------------------------------------------------------------------
-# pytree family: jax.tree.* is the modern spelling but 0.4.x only carries
-# the full set under jax.tree_util (jax.tree.flatten_with_path in
-# particular is missing on 0.4.37).  jax.tree_util has every spelling on
-# all supported versions, so bind the whole family there.
+# pytree family
 # ---------------------------------------------------------------------------
 
-tree_map = jtu.tree_map
-tree_leaves = jtu.tree_leaves
-tree_flatten = jtu.tree_flatten
-tree_unflatten = jtu.tree_unflatten
-tree_structure = jtu.tree_structure
-tree_flatten_with_path = jtu.tree_flatten_with_path
-tree_map_with_path = jtu.tree_map_with_path
+tree_map = jax.tree.map
+tree_leaves = jax.tree.leaves
+tree_flatten = jax.tree.flatten
+tree_unflatten = jax.tree.unflatten
+tree_structure = jax.tree.structure
+tree_flatten_with_path = jax.tree.flatten_with_path
+tree_map_with_path = jax.tree.map_with_path
 keystr = jtu.keystr
 
 
@@ -66,75 +58,26 @@ keystr = jtu.keystr
 # mesh construction
 # ---------------------------------------------------------------------------
 
-_HAS_AXIS_TYPES = hasattr(jax.sharding, "AxisType")
-
 
 def default_axis_types(n: int):
-    """``(AxisType.Auto,) * n`` where AxisType exists, else None."""
-    if _HAS_AXIS_TYPES:
-        return (jax.sharding.AxisType.Auto,) * n
-    return None
+    """``(AxisType.Auto,) * n``."""
+    return (jax.sharding.AxisType.Auto,) * n
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               axis_types: Any = None, devices=None) -> Mesh:
-    """``jax.make_mesh`` that tolerates ``axis_types`` on every version.
-
-    On 0.4.x (no ``AxisType``) the kwarg is dropped — those releases have
-    no explicit-sharding mode, so Auto is the only (implicit) behaviour
-    anyway.  ``axis_types=True`` asks for the version's default Auto types.
-    """
-    kwargs: Dict[str, Any] = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if axis_types is not None and _HAS_AXIS_TYPES:
-        if axis_types is True:
-            axis_types = default_axis_types(len(axis_shapes))
-        kwargs["axis_types"] = axis_types
-    if hasattr(jax, "make_mesh"):
-        try:
-            return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                                 **kwargs)
-        except TypeError:
-            # e.g. 0.4.35-0.4.38: make_mesh exists but without axis_types
-            kwargs.pop("axis_types", None)
-            return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                                 **kwargs)
-    from jax.experimental import mesh_utils
-    devs = mesh_utils.create_device_mesh(tuple(axis_shapes),
-                                         devices=devices)
-    return Mesh(devs, tuple(axis_names))
+    """``jax.make_mesh``; ``axis_types=True`` asks for Auto axes."""
+    if axis_types is True:
+        axis_types = default_axis_types(len(axis_shapes))
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=axis_types, devices=devices)
 
 
 # ---------------------------------------------------------------------------
 # shard_map entry point
 # ---------------------------------------------------------------------------
 
-try:  # jax >= 0.7 exposes shard_map at top level
-    from jax import shard_map as _shard_map_impl  # type: ignore[attr-defined]
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-import inspect as _inspect
-
-_SHARD_MAP_PARAMS = frozenset(
-    _inspect.signature(_shard_map_impl).parameters)
-
-
-def shard_map(f: Optional[Callable] = None, **kwargs):
-    """``shard_map`` with the replication-check kwarg normalized.
-
-    Newer JAX renamed ``check_rep`` to ``check_vma``; callers use the
-    modern spelling and this translates for 0.4.x.  All other kwargs pass
-    through untouched.
-    """
-    if "check_vma" in kwargs and "check_vma" not in _SHARD_MAP_PARAMS:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    elif "check_rep" in kwargs and "check_rep" not in _SHARD_MAP_PARAMS:
-        kwargs["check_vma"] = kwargs.pop("check_rep")
-    if f is None:
-        return lambda g: _shard_map_impl(g, **kwargs)
-    return _shard_map_impl(f, **kwargs)
+shard_map = jax.shard_map
 
 
 # ---------------------------------------------------------------------------
@@ -143,17 +86,6 @@ def shard_map(f: Optional[Callable] = None, **kwargs):
 
 
 def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """Normalize ``compiled.cost_analysis()`` to one flat dict.
-
-    0.4.x returns ``[{...}]`` (one dict per partition — a single dict for
-    the single-partition programs we lower); newer JAX returns the dict
-    directly.  Missing/empty analyses normalize to ``{}``.
-    """
-    cost = compiled.cost_analysis()
-    if cost is None:
-        return {}
-    if isinstance(cost, (list, tuple)):
-        if not cost:
-            return {}
-        cost = cost[0]
-    return dict(cost)
+    """``compiled.cost_analysis()`` as a flat dict (``{}`` when the
+    backend reports no analysis)."""
+    return dict(compiled.cost_analysis() or {})

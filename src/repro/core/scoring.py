@@ -20,9 +20,8 @@ This engine runs the whole pool as ONE jit-compiled program:
 * microbatch counts are bucketed to powers of two so a shrinking
   candidate set re-uses O(log N) compiled programs instead of recompiling
   every MCAL iteration;
-* the padded pool buffer is donated to the computation (where the backend
-  supports donation) and top-k candidate selection happens on device
-  (``lax.top_k`` over the packed scores, padding masked to -inf);
+* top-k candidate selection happens on device (``lax.top_k`` over the
+  packed scores, padding masked to -inf);
 * the same sweep optionally emits pooled last-hidden-state features
   (``ScoringConfig.with_features`` / :meth:`PoolScoringEngine.pool_features`)
   which stay device-resident — the k-center selection engine
@@ -114,14 +113,14 @@ def stats_from_confidence(conf: np.ndarray, num_classes: int,
 
 
 def head_stats(hidden: jax.Array, w_head: jax.Array, *, mode: str = "auto",
-               vocab_chunk: int = 8192, pallas_interpret: bool = True,
-               pallas_bt: int = 128, pallas_bv: int = 512) -> ScoreStats:
+               vocab_chunk: int = 8192, pallas_bt: int = 128,
+               pallas_bv: int = 512) -> ScoreStats:
     """Fused vocab projection + ScoreStats for last-token hidden states.
 
     ``hidden``: (T, D); ``w_head``: (D, V).  ``mode``:
       dense    materialize (T, V) logits (exact reference; small V),
       chunked  online top-2/logsumexp over vocab chunks (jnp),
-      pallas   the ``margin_head`` TPU kernel,
+      pallas   the ``margin_head`` TPU kernel (interpreted off-TPU),
       auto     dense when V fits comfortably, else chunked.
     """
     V = w_head.shape[-1]
@@ -134,10 +133,11 @@ def head_stats(hidden: jax.Array, w_head: jax.Array, *, mode: str = "auto",
     if mode == "chunked":
         return L.chunked_score_stats(hidden, w_head, chunk=vocab_chunk)
     if mode == "pallas":
+        from repro.kernels import ops
         from repro.kernels.margin_head import margin_head
         margin, entropy, max_logprob, top1 = margin_head(
             hidden, w_head, bt=pallas_bt, bv=pallas_bv,
-            interpret=pallas_interpret)
+            interpret=ops._interpret())
         return ScoreStats(margin=margin, entropy=entropy,
                           max_logprob=max_logprob, top1=top1)
     raise ValueError(f"unknown head mode {mode!r}")
@@ -153,10 +153,8 @@ class ScoringConfig:
     microbatch: int = 1024
     head_mode: str = "auto"        # auto | dense | chunked | pallas
     vocab_chunk: int = 8192
-    pallas_interpret: bool = True  # interpret kernels off-TPU
     pallas_bt: int = 128
     pallas_bv: int = 512
-    donate_pool: bool = True       # donate the padded pool buffer
     with_features: bool = True     # also return last-hidden features
 
 
@@ -175,9 +173,9 @@ class PoolScoringEngine:
         self.mesh = mesh
         self._batch_key = ("features" if model.cfg.family == "mlp"
                            else "tokens")
-        donate = cfg.donate_pool and jax.default_backend() != "cpu"
-        self._donate = donate
-        kwargs = {"donate_argnums": (1,) if donate else ()}
+        # no pool donation: no output has the packed pool's shape, so a
+        # donated buffer could never be reused
+        kwargs = {}
         if mesh is not None:
             xs_spec = NamedSharding(mesh, P(None, "data"))
             p_spec = NamedSharding(mesh, P())
@@ -212,7 +210,6 @@ class PoolScoringEngine:
         w = resolve_head_weight(self.model.cfg, params)
         stats = head_stats(h, w.astype(jnp.float32),
                            mode=c.head_mode, vocab_chunk=c.vocab_chunk,
-                           pallas_interpret=c.pallas_interpret,
                            pallas_bt=c.pallas_bt, pallas_bv=c.pallas_bv)
         return stats, h
 
@@ -246,10 +243,6 @@ class PoolScoringEngine:
         if pad:
             x = jnp.concatenate(
                 [x, jnp.zeros((pad,) + x.shape[1:], x.dtype)])
-        elif self._donate and isinstance(pool_x, jax.Array):
-            # donation would otherwise invalidate the caller's own buffer
-            # (asarray/reshape alias device arrays when no padding copies)
-            x = jnp.copy(x)
         self._note_pack((n_mb, mb))
         return x.reshape((n_mb, mb) + x.shape[1:]), n
 
@@ -261,8 +254,7 @@ class PoolScoringEngine:
         runtime's page kernel (``serving.sweep.EngineSweepAdapter``).
         Returns PACKED statistics/features (padding rows included; the
         caller masks by its own valid count).  Shares the compile cache
-        with :meth:`score`, and donates the page buffer where the backend
-        supports donation."""
+        with :meth:`score`."""
         self._note_pack((int(xs.shape[0]), int(xs.shape[1])))
         return self._run_packed(params, xs)
 

@@ -44,26 +44,29 @@ def _kernel(h_ref, w_ref, margin_ref, ent_ref, mlp_ref, top1_ref,
     valid = col < V
     x = jnp.where(valid, x, NEG_INF)
 
-    # online logsumexp + sum(x * e^x) (entropy numerator)
+    # online logsumexp + sum(x * e^x) (entropy numerator); every per-row
+    # statistic is a (bt, 1) column so it keeps the tile's sublane layout
     m_old, s_old, u_old = m_sc[:], s_sc[:], u_sc[:]
-    cm = jnp.max(x, axis=-1)
+    cm = jnp.max(x, axis=-1, keepdims=True)
     m_new = jnp.maximum(m_old, cm)
     corr = jnp.exp(m_old - m_new)
-    e = jnp.exp(x - m_new[:, None])
-    s_sc[:] = s_old * corr + jnp.sum(e, axis=-1)
-    u_sc[:] = u_old * corr + jnp.sum(jnp.where(valid, x, 0.0) * e, axis=-1)
+    e = jnp.exp(x - m_new)
+    s_sc[:] = s_old * corr + jnp.sum(e, axis=-1, keepdims=True)
+    u_sc[:] = u_old * corr + jnp.sum(jnp.where(valid, x, 0.0) * e, axis=-1,
+                                     keepdims=True)
     m_sc[:] = m_new
 
-    # online top-2 merge: tile top-2 vs carried top-2
-    c1 = jnp.max(x, axis=-1)
-    a1 = jnp.argmax(x, axis=-1)  # local tile index
+    # online top-2 merge: tile top-2 vs carried top-2.  The tile argmax is
+    # the first column attaining the max (min over matching iotas)
     local = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-    x2 = jnp.where(local == a1[:, None], NEG_INF, x)
-    c2 = jnp.max(x2, axis=-1)
+    c1 = cm
+    a1 = jnp.min(jnp.where(x == c1, local, bv), axis=-1, keepdims=True)
+    x2 = jnp.where(local == a1, NEG_INF, x)
+    c2 = jnp.max(x2, axis=-1, keepdims=True)
     v1_old, v2_old, i1_old = v1_sc[:], v2_sc[:], i1_sc[:]
     v1_new = jnp.maximum(v1_old, c1)
     v2_new = jnp.maximum(jnp.minimum(v1_old, c1), jnp.maximum(v2_old, c2))
-    i1_sc[:] = jnp.where(c1 > v1_old, a1.astype(jnp.int32) + vi * bv, i1_old)
+    i1_sc[:] = jnp.where(c1 > v1_old, a1 + vi * bv, i1_old)
     v1_sc[:] = v1_new
     v2_sc[:] = v2_new
 
@@ -99,13 +102,15 @@ def margin_head(hidden: jax.Array, w_vocab: jax.Array, *,
         w_vocab = jnp.pad(w_vocab, ((0, 0), (0, Vp - V)))
     grid = (Tp // bt, Vp // bv)
 
+    # per-row statistics travel as (Tp, 1) columns: Mosaic refuses 1-D
+    # (bt,) blocks whose tiling differs from XLA's layout of the array
     out_shape = [
-        jax.ShapeDtypeStruct((Tp,), jnp.float32),  # margin
-        jax.ShapeDtypeStruct((Tp,), jnp.float32),  # entropy
-        jax.ShapeDtypeStruct((Tp,), jnp.float32),  # max_logprob
-        jax.ShapeDtypeStruct((Tp,), jnp.int32),    # top1
+        jax.ShapeDtypeStruct((Tp, 1), jnp.float32),  # margin
+        jax.ShapeDtypeStruct((Tp, 1), jnp.float32),  # entropy
+        jax.ShapeDtypeStruct((Tp, 1), jnp.float32),  # max_logprob
+        jax.ShapeDtypeStruct((Tp, 1), jnp.int32),    # top1
     ]
-    stat_spec = pl.BlockSpec((bt,), lambda t, v: (t,))
+    stat_spec = pl.BlockSpec((bt, 1), lambda t, v: (t, 0))
     outs = pl.pallas_call(
         functools.partial(_kernel, V=V, bv=bv),
         grid=grid,
@@ -116,13 +121,13 @@ def margin_head(hidden: jax.Array, w_vocab: jax.Array, *,
         out_specs=[stat_spec] * 4,
         out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((bt,), jnp.float32),  # m
-            pltpu.VMEM((bt,), jnp.float32),  # s
-            pltpu.VMEM((bt,), jnp.float32),  # u
-            pltpu.VMEM((bt,), jnp.float32),  # v1
-            pltpu.VMEM((bt,), jnp.float32),  # v2
-            pltpu.VMEM((bt,), jnp.int32),    # i1
+            pltpu.VMEM((bt, 1), jnp.float32),  # m
+            pltpu.VMEM((bt, 1), jnp.float32),  # s
+            pltpu.VMEM((bt, 1), jnp.float32),  # u
+            pltpu.VMEM((bt, 1), jnp.float32),  # v1
+            pltpu.VMEM((bt, 1), jnp.float32),  # v2
+            pltpu.VMEM((bt, 1), jnp.int32),    # i1
         ],
         interpret=interpret,
     )(hidden, w_vocab)
-    return tuple(o[:T] for o in outs)
+    return tuple(o[:T, 0] for o in outs)

@@ -1,12 +1,16 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (architecture x input shape)
 cell on the production meshes and extract the roofline inputs.
 
-The two lines above MUST run before any other import (jax locks the device
-count on first init), which is why this module sets XLA_FLAGS at the very
-top and why nothing else in the repo sets it globally.
+The lines above MUST run before any other import (jax locks the backend
+and device count on first init), which is why this module sets them at
+the very top and why nothing else in the repo sets them globally.  The
+dry-run is written for 512 forced host devices: ``JAX_PLATFORMS=cpu``
+keeps it (and every ``--sweep`` child, which inherits the environment)
+off an attached accelerator, which only one process may hold.
 
 Per cell this emits JSON:
   flops            — compiled.cost_analysis()["flops"]

@@ -406,6 +406,48 @@ def run_campaign(task, service, cfg, *, state_path: str = "",
             trace.close()
 
 
+def build_campaign(args):
+    """The campaign ``main()`` runs for parsed ``args``: ``(task,
+    service, cfg, annotation)`` — a :class:`~repro.core.task.LiveTask`
+    over a seeded synthetic pool (``--live``) or an emulated replay,
+    with the annotation runtime the flags describe (None for the perfect
+    oracle)."""
+    from repro.core import (MCALConfig, SERVICES, LiveTask,
+                            make_emulated_task)
+    from repro.data.synth import make_classification
+
+    service = SERVICES[args.service]
+    if args.live:
+        num_classes = args.classes
+    else:
+        from repro.core.emulator import DATASETS
+        num_classes = DATASETS[args.dataset]["classes"]
+    annotation = build_annotation(args, num_classes, service)
+    cfg = MCALConfig(eps_target=args.eps, metric=args.metric,
+                     budget=args.budget, seed=args.seed,
+                     sweep_async=args.sweep_async,
+                     fit_async=args.fit_async,
+                     # measured (calibration-batch) quality: what DS +
+                     # adaptive repeats actually deliver, deterministic
+                     # per seed so resumed runs rebuild the same config
+                     label_quality=(annotation.calibrate()
+                                    if annotation is not None else None))
+    if args.live:
+        x, y = make_classification(args.pool, num_classes=args.classes,
+                                   difficulty=args.difficulty,
+                                   seed=args.seed)
+        task = LiveTask(features=x, groundtruth=y, num_classes=args.classes,
+                        seed=args.seed, sweep_page=args.sweep_page,
+                        fit_fused=args.fit_fused,
+                        fit_resident=args.fit_resident,
+                        mesh=build_mesh(args.mesh), annotation=annotation)
+    else:
+        task = make_emulated_task(args.dataset, args.arch, seed=args.seed,
+                                  sweep_page=args.sweep_page)
+        task.annotation = annotation
+    return task, service, cfg, annotation
+
+
 def main():
     args = build_parser().parse_args()
 
@@ -444,39 +486,9 @@ def main():
                 json.dump(report, f)
         return
 
-    from repro.core import (MCALConfig, SERVICES, LiveTask,
-                            make_emulated_task)
-    from repro.data.synth import make_classification
-
-    service = SERVICES[args.service]
-    if args.live:
-        num_classes = args.classes
-    else:
-        from repro.core.emulator import DATASETS
-        num_classes = DATASETS[args.dataset]["classes"]
-    annotation = build_annotation(args, num_classes, service)
-    cfg = MCALConfig(eps_target=args.eps, metric=args.metric,
-                     budget=args.budget, seed=args.seed,
-                     sweep_async=args.sweep_async,
-                     fit_async=args.fit_async,
-                     # measured (calibration-batch) quality: what DS +
-                     # adaptive repeats actually deliver, deterministic
-                     # per seed so resumed runs rebuild the same config
-                     label_quality=(annotation.calibrate()
-                                    if annotation is not None else None))
-    if args.live:
-        x, y = make_classification(args.pool, num_classes=args.classes,
-                                   difficulty=args.difficulty,
-                                   seed=args.seed)
-        task = LiveTask(features=x, groundtruth=y, num_classes=args.classes,
-                        seed=args.seed, sweep_page=args.sweep_page,
-                        fit_fused=args.fit_fused,
-                        fit_resident=args.fit_resident,
-                        mesh=build_mesh(args.mesh), annotation=annotation)
-    else:
-        task = make_emulated_task(args.dataset, args.arch, seed=args.seed,
-                                  sweep_page=args.sweep_page)
-        task.annotation = annotation
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
+    task, service, cfg, annotation = build_campaign(args)
 
     faults = retry = None
     if args.chaos:

@@ -553,6 +553,8 @@ def main():
         health = HealthEngine(SLOSpec.load(args.slo))
     elif args.slo_enforce:
         raise SystemExit("--slo-enforce requires --slo SPEC.json")
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     orch = build_fleet(x, y, specs, service=service,
                        global_budget=args.global_budget,
                        trace_dir=args.trace_dir,

@@ -15,8 +15,8 @@ runtime around that program:
   compiled program as an unpaged sweep;
 * pages are **double-buffered**: the host→device transfer of page i+1 is
   enqueued while page i's compute is in flight (JAX async dispatch), and
-  the page buffer is donated to the scoring step where the backend
-  supports donation — peak device memory is O(page), not O(pool);
+  each page buffer is released once its page is scored — peak device
+  memory is O(page), not O(pool);
 * each page folds into a pluggable **sink** that keeps its running state
   device-resident, so pool-wide statistics never materialize on the host:
     - :class:`TopKSink`       M(.): top-k uncertainty reservoir

@@ -22,8 +22,8 @@ as ONE jit-compiled device program:
   epoch's permutation (``(s*bs + arange(bs)) % n``) exactly like the host
   loop's wrap, so padding rows are never trained on and no masked loss is
   needed;
-* the train state is donated into the program (where the backend supports
-  donation) and threaded through the scan carry;
+* the freshly initialized train state is donated into the program and
+  threaded through the scan carry;
 * ``(n, batch)`` is bucketed through the same :func:`scoring.pack_shape`
   convention as every other device engine (``(steps_per_epoch, bs) =
   pack_shape(n, batch_size)``, padded pool = ``steps_per_epoch * bs``
@@ -111,7 +111,6 @@ _epoch_orders_jit = jax.jit(epoch_orders, static_argnums=(1, 2))
 class FitConfig:
     epochs: int = 40
     batch_size: int = 256
-    donate_state: bool = True   # donate the init state into the program
 
 
 class FitEngine:
@@ -168,9 +167,6 @@ class FitEngine:
 
     # -- program construction ------------------------------------------------
 
-    def _donate(self) -> bool:
-        return self.cfg.donate_state and jax.default_backend() != "cpu"
-
     def _program(self, n: int):
         """The fused program for the ``fit_plan`` bucket of ``n`` (compile
         cache keyed on the bucket, not the raw size)."""
@@ -199,8 +195,8 @@ class FitEngine:
                 body, state, jnp.arange(epochs * spe, dtype=jnp.int32))
             return state, losses
 
-        kwargs: Dict[str, Any] = {
-            "donate_argnums": (0,) if self._donate() else ()}
+        # the state is built fresh per fit, so donating it is always safe
+        kwargs: Dict[str, Any] = {"donate_argnums": (0,)}
         if self.mesh is not None:
             _, pspecs = state_pspecs(self.model, self.tc, self.mesh,
                                      self.policy)

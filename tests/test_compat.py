@@ -1,26 +1,15 @@
-"""The version-adaptive JAX shim, exercised on BOTH CI matrix legs.
-
-Everything here runs on the 0.4.37 floor and on recent jax — the same
-test asserts whichever behaviour the installed version should produce,
-probing via the compat module's own feature detection.  Tests that only
-make sense on one side use a compat SKIP (never an xfail): a skip states
-"this API legitimately does not exist here", an xfail would claim the
-test is expected to break.
-"""
+"""The JAX shim: every public name binds to the JAX 0.9 API."""
 import numpy as np
-import pytest
 
 import jax
 import jax.numpy as jnp
 
 from repro import compat
 
-HAS_AXIS_TYPES = hasattr(jax.sharding, "AxisType")
-
 
 def test_version_tuple_parsed():
     assert len(compat.JAX_VERSION) >= 2
-    assert compat.JAX_VERSION >= (0, 4, 37)
+    assert compat.JAX_VERSION >= (0, 9)
 
 
 def test_tree_family_roundtrip():
@@ -34,7 +23,7 @@ def test_tree_family_roundtrip():
 
 
 def test_tree_flatten_with_path_spellings():
-    """flatten_with_path + keystr — the 0.4.x gap that motivated the shim."""
+    """flatten_with_path + keystr (keystr has no jax.tree spelling)."""
     tree = {"w": jnp.ones(2), "b": jnp.zeros(1)}
     flat = compat.tree_flatten_with_path(tree)[0]
     keys = sorted(compat.keystr(path) for path, _ in flat)
@@ -45,31 +34,20 @@ def test_tree_flatten_with_path_spellings():
 
 
 def test_make_mesh_tolerates_axis_types_everywhere():
-    """axis_types=True must construct a mesh on every supported version —
-    dropped on 0.4.x, defaulted Auto types on newer jax."""
+    """axis_types=True builds a mesh with Auto axes."""
     mesh = compat.make_mesh((1,), ("data",), axis_types=True)
     assert mesh.axis_names == ("data",)
     assert mesh.devices.size == 1
+    assert mesh.axis_types == compat.default_axis_types(1)
 
 
-@pytest.mark.skipif(not HAS_AXIS_TYPES,
-                    reason="jax < AxisType: explicit axis types do not "
-                           "exist on this version (compat skip)")
 def test_default_axis_types_modern():
     types = compat.default_axis_types(2)
     assert types == (jax.sharding.AxisType.Auto,) * 2
 
 
-@pytest.mark.skipif(HAS_AXIS_TYPES,
-                    reason="jax >= AxisType: legacy None-default only "
-                           "applies below it (compat skip)")
-def test_default_axis_types_legacy():
-    assert compat.default_axis_types(2) is None
-
-
 def test_shard_map_normalizes_replication_kwarg():
-    """Callers use the modern check_vma spelling; the shim must translate
-    for 0.4.x (check_rep) and pass through on newer jax."""
+    """Callers use the check_vma spelling of jax.shard_map."""
     mesh = compat.make_mesh((1,), ("data",))
     P = compat.PartitionSpec
 
@@ -83,8 +61,7 @@ def test_shard_map_normalizes_replication_kwarg():
 
 
 def test_cost_analysis_dict_normalizes_shapes():
-    """List-of-dicts (0.4.x), plain dict (newer), None, and empty list all
-    normalize to one flat dict."""
+    """A dict passes through as a copy; no analysis is an empty dict."""
 
     class Fake:
         def __init__(self, ret):
@@ -93,15 +70,14 @@ def test_cost_analysis_dict_normalizes_shapes():
         def cost_analysis(self):
             return self._ret
 
-    assert compat.cost_analysis_dict(Fake([{"flops": 2.0}])) == \
-        {"flops": 2.0}
-    assert compat.cost_analysis_dict(Fake({"flops": 3.0})) == {"flops": 3.0}
+    ret = {"flops": 3.0}
+    assert compat.cost_analysis_dict(Fake(ret)) == {"flops": 3.0}
+    assert compat.cost_analysis_dict(Fake(ret)) is not ret
     assert compat.cost_analysis_dict(Fake(None)) == {}
-    assert compat.cost_analysis_dict(Fake([])) == {}
 
 
 def test_cost_analysis_dict_on_real_compiled():
-    """Whatever shape the installed jax returns, the shim yields a dict."""
+    """A real compiled program's analysis comes back as a dict."""
     compiled = jax.jit(lambda x: x @ x).lower(
         jnp.ones((8, 8), jnp.float32)).compile()
     cost = compat.cost_analysis_dict(compiled)

@@ -212,3 +212,48 @@ def test_run_campaign_resume_preserves_random_metric_stream(tmp_path):
     np.testing.assert_array_equal(camp.pool.B_idx, plain_camp.pool.B_idx)
     assert res.total_cost == _pytest.approx(plain.total_cost, rel=1e-9)
     np.testing.assert_array_equal(res.labels, plain.labels)
+
+
+def test_compile_cache_leaves_a_set_dir_to_jax(monkeypatch, tmp_path):
+    import jax
+    from repro.launch.cache import enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_defaults_to_a_fixed_ignored_dir(monkeypatch):
+    import os
+
+    import jax
+    from repro.launch import cache
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert cache.DEFAULT_CACHE_DIR == os.path.join(root, ".jax_cache")
+    with open(os.path.join(root, ".gitignore")) as f:
+        assert "/.jax_cache/" in f.read().split()
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        assert cache.enable_compile_cache() == cache.DEFAULT_CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == cache.DEFAULT_CACHE_DIR
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_dryrun_pins_itself_and_its_children_to_the_cpu():
+    """The dry-run forces 512 host devices; JAX_PLATFORMS=cpu beside
+    XLA_FLAGS keeps it and every --sweep child off an attached chip."""
+    import os
+    import subprocess
+    import sys
+
+    env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
+    env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import os, repro.launch.dryrun; print(os.environ['JAX_PLATFORMS'])"],
+        env=env, capture_output=True, text=True, timeout=120,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip() == "cpu"
