@@ -519,6 +519,12 @@ class MCALCampaign:
 
     def search(self, keep_surface: Optional[bool] = None) -> SearchResult:
         self._sync_fit()
+        with self._mspan("search"):
+            return self._search_impl(keep_surface)
+
+    def _search_impl(self, keep_surface: Optional[bool]) -> SearchResult:
+        """The power-law and cost-model fits, then the joint (or budget)
+        search over them (the ``search`` span)."""
         laws, cm = self._fit_models()
         p = self.pool
         kw = dict(pool_size=self.task.pool_size, test_size=len(p.T_idx),
@@ -552,8 +558,6 @@ class MCALCampaign:
                                        forced_acquisition=forced_acquisition)
         if self.metrics is not None:
             self.metrics.inc("campaign_iterations_total")
-            self.metrics.set_gauge("campaign_spent_total",
-                                   float(self.pool.ledger.total))
         if self.health is not None:
             self.health.tick_campaign(self)
         return rec

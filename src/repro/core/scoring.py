@@ -287,8 +287,6 @@ class PoolScoringEngine:
             self._compiled[key] = self._score_all.lower(params, xs).compile()
             self.pack_keys.add(key)
             count += 1
-        if count and self.metrics is not None:
-            self.metrics.inc("warm_compiles_total", count, engine="scoring")
         return count
 
     def score(self, params, pool_x) -> Tuple[ScoreStats, jax.Array]:

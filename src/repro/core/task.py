@@ -161,8 +161,9 @@ class LiveTask:
 
     def attach_metrics(self, metrics) -> None:
         """Wire the runtime metrics registry (repro.obs) through this
-        task's engine stack: sweep page/fold timings, fit spans +
-        compile-cache hit/miss counters, and the k-center span.  Unlike
+        task's engine stack: the host gather span, sweep spans + swept
+        rows, fit spans + compile-cache hit/miss counters, and the
+        k-center span.  Unlike
         :meth:`attach_trace`, SHARED engines are wired too — the fleet
         hands every tenant the same registry and attributes per-tenant
         time via the orchestrator's bound ``tenant`` label, so there is
@@ -232,8 +233,7 @@ class LiveTask:
         t0 = time.perf_counter()
         if not self.fit_fused:
             params, losses = self._fit.fit_reference(
-                rng, self.features[idx].astype(np.float32),
-                np.asarray(labels, np.int32))
+                rng, self._rows(idx), np.asarray(labels, np.int32))
         elif self.fit_resident:
             prev = len(self._res_idx)
             if n < prev or not np.array_equal(idx[:prev], self._res_idx):
@@ -243,14 +243,12 @@ class LiveTask:
             if n > prev:
                 fresh = idx[prev:]
                 self._fit.extend_resident(
-                    self.features[fresh].astype(np.float32),
-                    np.asarray(labels, np.int32)[prev:])
+                    self._rows(fresh), np.asarray(labels, np.int32)[prev:])
             self._res_idx = idx.copy()
             params, losses = self._fit.fit_resident(rng)
         else:
             params, losses = self._fit.fit(
-                rng, self.features[idx].astype(np.float32),
-                np.asarray(labels, np.int32))
+                rng, self._rows(idx), np.asarray(labels, np.int32))
         jax.block_until_ready(losses)
         wall = time.perf_counter() - t0
         self._params = params
@@ -294,9 +292,19 @@ class LiveTask:
     # ``repro.core.scoring.score_pool_reference`` (the oracle the engine
     # is validated against and benchmarked over).
 
+    def _rows(self, idx: np.ndarray) -> np.ndarray:
+        """The float32 feature rows of ``idx``: the host gather that
+        feeds every device pass (the ``gather`` span, outside the ``fit``
+        and ``sweep`` spans of the work it feeds)."""
+        idx = np.asarray(idx, np.int64)
+        if self.metrics is None:
+            return self.features[idx].astype(np.float32)
+        with self.metrics.span("gather"):
+            return self.features[idx].astype(np.float32)
+
     def _pool(self, idx: np.ndarray) -> np.ndarray:
         assert self._params is not None, "train() before score()"
-        return self.features[np.asarray(idx, np.int64)].astype(np.float32)
+        return self._rows(idx)
 
     def score(self, idx: np.ndarray):
         stats, feats = self._engine.score_host(self._params, self._pool(idx))

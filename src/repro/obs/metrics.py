@@ -1,6 +1,7 @@
 # Low-overhead runtime metrics: counters, gauges, log-bucket histograms,
-# nested spans.  jax-free by construction (the optional device fence
-# imports jax lazily) so report tooling can import it anywhere.
+# nested spans.  jax-free at import (an open span's profiler annotation
+# and the optional device fence import jax lazily) so report tooling can
+# import it anywhere.
 """Runtime metrics & profiling registry (the obs/ half of observability).
 
 Division of labor with ``repro.trace``: the trace store records the
@@ -33,7 +34,11 @@ Spans nest per thread::
             out = adapter.score(params, page)
             sp.fence(out)        # block_until_ready at span exit
 
-and a :class:`Span` doubles as a decorator.  ``registry.bind(tenant=t)``
+and a :class:`Span` doubles as a decorator.  While it is open a span
+also holds a ``jax.profiler.TraceAnnotation`` named ``repro:<name>``, so
+under a profiler (``jax.profiler.start_trace``, the launcher's
+``--profile``) the program's spans appear as nested host events on the
+same clock as the device's programs.  ``registry.bind(tenant=t)``
 pushes thread-local labels onto everything recorded by that thread —
 the orchestrator wraps each tenant round in a bind so shared-engine
 spans attribute per tenant without threading ids through every call.
@@ -124,9 +129,12 @@ class Span:
     at exit, so the recorded time covers the device work the span
     dispatched, not just the host-side submit.  An exception unwinds
     the stack normally and stamps the span ``status="error"`` (and is
-    re-raised — spans never swallow)."""
+    re-raised — spans never swallow).  The span's profiler annotation
+    (``repro:<name>``) opens on entry and closes after the fence, on the
+    error path too."""
 
-    __slots__ = ("registry", "name", "labels", "path", "_t0", "_fences")
+    __slots__ = ("registry", "name", "labels", "path", "_t0", "_fences",
+                 "_annotation")
 
     def __init__(self, registry: "MetricsRegistry", name: str,
                  labels: Dict[str, object]):
@@ -136,6 +144,7 @@ class Span:
         self.path = name
         self._t0 = 0.0
         self._fences: List[object] = []
+        self._annotation = None
 
     def fence(self, value: object) -> None:
         """Queue a device value for block_until_ready at span exit."""
@@ -143,6 +152,10 @@ class Span:
             self._fences.append(value)
 
     def __enter__(self) -> "Span":
+        from jax.profiler import TraceAnnotation  # lazy, as in __exit__
+
+        self._annotation = TraceAnnotation("repro:" + self.name)
+        self._annotation.__enter__()
         stack = self.registry._span_stack()
         if stack:
             self.path = stack[-1].path + "/" + self.name
@@ -152,12 +165,15 @@ class Span:
 
     def __exit__(self, etype, evalue, tb) -> bool:
         fenced = False
-        if self._fences and etype is None:
-            import jax  # lazy: the registry itself stays jax-free
+        try:
+            if self._fences and etype is None:
+                import jax  # lazy: the registry itself stays jax-free
 
-            jax.block_until_ready(self._fences)
-            fenced = True
-        seconds = time.perf_counter() - self._t0
+                jax.block_until_ready(self._fences)
+                fenced = True
+            seconds = time.perf_counter() - self._t0
+        finally:
+            self._annotation.__exit__(etype, evalue, tb)
         stack = self.registry._span_stack()
         if stack and stack[-1] is self:
             stack.pop()

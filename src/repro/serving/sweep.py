@@ -65,7 +65,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import json
-import time
 from types import SimpleNamespace
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -628,38 +627,23 @@ class PoolSweepRunner:
                n: int):
         P = self.cfg.page_rows
         m = self.metrics
-        clock = time.perf_counter
         queue: List = []
         nxt = start
         depth = max(self.cfg.prefetch, 1)
 
         def put_page(i: int):
-            # h2d submit latency (the transfer itself overlaps compute)
-            t0 = clock() if m is not None else 0.0
-            out = self.adapter.put(pool, i * P, min((i + 1) * P, n))
-            if m is not None:
-                m.observe("sweep_put_seconds", clock() - t0)
-            return out
+            return self.adapter.put(pool, i * P, min((i + 1) * P, n))
 
         while nxt < stop and len(queue) < depth:
             queue.append(put_page(nxt))
             nxt += 1
         for p in range(start, stop):
             page, nvalid = queue.pop(0)
-            t0 = clock() if m is not None else 0.0
             stats, feats = self.adapter.score(params, page)  # async dispatch
-            if m is not None:
-                # dispatch-side latency only: device compute stays async
-                # and overlaps the next page's h2d below
-                m.observe("sweep_score_submit_seconds", clock() - t0)
             if nxt < stop:   # h2d of the next page overlaps this compute
                 queue.append(put_page(nxt))
                 nxt += 1
-            t0 = clock() if m is not None else 0.0
             state = sink.fold(state, stats, feats, p * P, nvalid)
             if m is not None:
-                # fold blocks on page i's results: the overlap window
-                m.observe("sweep_fold_seconds", clock() - t0)
-                m.inc("sweep_pages_total")
                 m.inc("sweep_rows_total", float(nvalid))
         return state

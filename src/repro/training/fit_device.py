@@ -180,7 +180,8 @@ class FitEngine:
             return prog, key
         epochs, step, batch_key = self.cfg.epochs, self._step, self._batch_key
 
-        def program(state, xp, yp, nn, key_data):
+        # named for the trace: the device program reads ``jit_fit_epochs``
+        def fit_epochs(state, xp, yp, nn, key_data):
             orders = epoch_orders(key_data, epochs, n_pad, nn)
 
             def body(state, t):
@@ -203,7 +204,7 @@ class FitEngine:
             rep = NamedSharding(self.mesh, P())
             kwargs["in_shardings"] = (shd.tree_named(self.mesh, pspecs),
                                       rep, rep, rep, rep)
-        prog = jax.jit(program, **kwargs)
+        prog = jax.jit(fit_epochs, **kwargs)
         self._programs[key] = prog
         return prog, key
 
@@ -380,10 +381,7 @@ class FitEngine:
         if self.metrics is None:
             return self._warm_impl(keys)
         with self.metrics.span("warm", engine="fit"):
-            count = self._warm_impl(keys)
-        if count:
-            self.metrics.inc("warm_compiles_total", count, engine="fit")
-        return count
+            return self._warm_impl(keys)
 
     def _warm_impl(self, keys) -> int:
         from repro.training.train_loop import abstract_train_state

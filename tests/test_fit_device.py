@@ -128,6 +128,21 @@ def test_warm_prebuilds_cache_from_keys():
     assert eng2.cache_keys() == keys
 
 
+def test_fused_retrain_lowers_under_its_own_name():
+    """The fused retrain's program is named for the device trace
+    (``jit_fit_epochs``), which reduces traces by program name."""
+    from repro.training.train_loop import abstract_train_state
+    model, tc, eng = _make_engine(epochs=1, batch=32)
+    prog, (_, _, n_pad) = eng._program(100)
+    ab_state, _ = abstract_train_state(model, tc)
+    text = prog.lower(
+        ab_state, jax.ShapeDtypeStruct((n_pad, 8), np.float32),
+        jax.ShapeDtypeStruct((n_pad,), np.int32),
+        jax.ShapeDtypeStruct((), np.int32),
+        jax.random.key_data(jax.random.key(0))).as_text()
+    assert "module @jit_fit_epochs" in text
+
+
 # ---------------------------------------------------------------------------
 # campaign-resident pool
 # ---------------------------------------------------------------------------

@@ -345,6 +345,89 @@ def test_disabled_mode_is_identity_on_engine_sites():
 
 
 # ---------------------------------------------------------------------------
+# spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+
+def test_spans_write_nested_profiler_annotations(tmp_path):
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    m = MetricsRegistry()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with m.span("outer"):
+            with m.span("inner") as sp:
+                sp.fence(jax.numpy.arange(8) * 2.0)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("repro:"):
+                        host[e.name] = (e.start_ns,
+                                        e.start_ns + e.duration_ns)
+    assert set(host) == {"repro:outer", "repro:inner"}
+    (o0, o1), (i0, i1) = host["repro:outer"], host["repro:inner"]
+    assert o0 <= i0 < i1 <= o1
+
+
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records what
+    opens and closes."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name):
+        log = self.log
+
+        class Annotation:
+            def __enter__(self):
+                log.append(("open", name))
+
+            def __exit__(self, etype, *exc):
+                log.append(("close", name, etype))
+
+        return Annotation()
+
+
+def test_span_that_raises_closes_its_annotation(monkeypatch):
+    import jax
+
+    ann = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", ann)
+    m = MetricsRegistry()
+    with pytest.raises(ValueError, match="boom"):
+        with m.span("outer"):
+            with m.span("inner"):
+                raise ValueError("boom")
+    assert ann.log == [("open", "repro:outer"), ("open", "repro:inner"),
+                       ("close", "repro:inner", ValueError),
+                       ("close", "repro:outer", ValueError)]
+
+
+def test_disabled_mode_builds_no_annotation(monkeypatch):
+    import jax
+    from repro.core.selection_device import k_center_greedy_device
+
+    def refuse(name):
+        raise AssertionError(f"annotation {name} built with metrics off")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+    X = np.random.default_rng(0).integers(0, 16, (128, 8)).astype(np.float32)
+    k_center_greedy_device(X, 10)
+    # and the patch is live: with a registry the same site builds one
+    with pytest.raises(AssertionError, match="repro:kcenter"):
+        k_center_greedy_device(X, 10, metrics=MetricsRegistry())
+
+
+# ---------------------------------------------------------------------------
 # report --metrics: solo + fleet, from recorded telemetry alone
 # ---------------------------------------------------------------------------
 
