@@ -42,6 +42,32 @@ class LabelingTask(Protocol):
     def eval_correct(self, idx: np.ndarray, labels: np.ndarray) -> np.ndarray: ...
 
 
+class RowView:
+    """Read-only view of the float32 rows ``features[idx]`` that gathers
+    only the slice asked for.  The paged sweep reads ``shape[0]`` and
+    ``view[lo:hi]``, so a pool pass copies each row once, a page at a
+    time, and the whole matrix of rows never exists on the host.  ``idx``
+    is copied, so a sweep submitted to a worker thread reads the rows it
+    was given even if the caller's index array changes."""
+
+    dtype = np.dtype(np.float32)
+
+    def __init__(self, features: np.ndarray, idx: np.ndarray):
+        self.features = features
+        self.idx = np.array(idx, np.int64)
+
+    @property
+    def shape(self) -> Tuple[int, ...]:
+        return (len(self.idx),) + self.features.shape[1:]
+
+    def __len__(self) -> int:
+        return len(self.idx)
+
+    def __getitem__(self, rows: slice) -> np.ndarray:
+        return np.take(self.features, self.idx[rows], axis=0).astype(
+            np.float32, copy=False)
+
+
 @dataclasses.dataclass
 class LiveTask:
     """MCAL over a real JAX classifier + feature dataset.
@@ -287,24 +313,35 @@ class LiveTask:
     # rank) stream through the paged pool-sweep runtime
     # (``serving.sweep.PoolSweepRunner`` over the device engine), so the
     # pool never materializes on the device and only each sink's fold
-    # returns to the host.  Small measurement scoring (the test set) stays
-    # on the direct engine path; the seed host loop survives as
+    # returns to the host.  A sweep takes a :class:`RowView` of its rows:
+    # the host gathers each page as the sweep stages it (inside the
+    # ``sweep`` span), and the whole candidate matrix is never built.
+    # Small measurement scoring (the test set) stays on the direct engine
+    # path over gathered rows; the seed host loop survives as
     # ``repro.core.scoring.score_pool_reference`` (the oracle the engine
     # is validated against and benchmarked over).
 
     def _rows(self, idx: np.ndarray) -> np.ndarray:
-        """The float32 feature rows of ``idx``: the host gather that
-        feeds every device pass (the ``gather`` span, outside the ``fit``
-        and ``sweep`` spans of the work it feeds)."""
+        """The float32 feature rows of ``idx`` in one copy: the host
+        gather that feeds the retrain (labeled set) and the direct engine
+        paths (test set) -- the ``gather`` span, outside the ``fit`` span
+        of the work it feeds.  Sweeps gather page by page instead
+        (:meth:`_pages`)."""
         idx = np.asarray(idx, np.int64)
         if self.metrics is None:
-            return self.features[idx].astype(np.float32)
+            return self.features[idx].astype(np.float32, copy=False)
         with self.metrics.span("gather"):
-            return self.features[idx].astype(np.float32)
+            return self.features[idx].astype(np.float32, copy=False)
 
     def _pool(self, idx: np.ndarray) -> np.ndarray:
         assert self._params is not None, "train() before score()"
         return self._rows(idx)
+
+    def _pages(self, idx: np.ndarray) -> RowView:
+        """The rows of ``idx`` for a paged sweep, gathered a page at a
+        time as the sweep stages them."""
+        assert self._params is not None, "train() before score()"
+        return RowView(self.features, idx)
 
     def score(self, idx: np.ndarray):
         stats, feats = self._engine.score_host(self._params, self._pool(idx))
@@ -315,7 +352,7 @@ class LiveTask:
         """M(.) fast path: paged sweep folding a device top-k reservoir —
         only the k chosen rows ever reach the host."""
         from repro.serving.sweep import TopKSink
-        rows = self._sweep.run(self._params, self._pool(candidates),
+        rows = self._sweep.run(self._params, self._pages(candidates),
                                TopKSink(k, metric))
         return np.asarray(candidates, np.int64)[rows]
 
@@ -328,7 +365,7 @@ class LiveTask:
         reference path."""
         from repro.core.selection_device import k_center_greedy_device
         from repro.serving.sweep import FeatureSink
-        feats = self._sweep.run(self._params, self._pool(candidates),
+        feats = self._sweep.run(self._params, self._pages(candidates),
                                 FeatureSink())
         rows = k_center_greedy_device(feats, k, anchors=anchors,
                                       metrics=self.metrics)
@@ -341,7 +378,7 @@ class LiveTask:
         anchor set, rebuildable from ``B_idx`` alone on resume."""
         from repro.serving.sweep import FeatureSink
         return np.asarray(
-            self._sweep.run(self._params, self._pool(idx), FeatureSink()),
+            self._sweep.run(self._params, self._pages(idx), FeatureSink()),
             np.float32)
 
     def machine_label_sweep(self, idx: np.ndarray, metric: str = "margin",
@@ -357,7 +394,7 @@ class LiveTask:
         and hand it to the callback — the launcher persists it in its
         ``--state`` file so a preempted commit sweep restarts mid-pool."""
         from repro.serving.sweep import RankTop1Sink
-        order, top1 = self._sweep.run(self._params, self._pool(idx),
+        order, top1 = self._sweep.run(self._params, self._pages(idx),
                                       RankTop1Sink(metric),
                                       checkpoint=checkpoint,
                                       checkpoint_every=checkpoint_every,
@@ -373,10 +410,11 @@ class LiveTask:
         to the same ``(picked, features)`` pair as
         :meth:`kcenter_candidates`."""
         from repro.serving.sweep import TopKSink
-        cand = np.asarray(candidates, np.int64)
+        # a copy: the worker gathers and maps rows after this returns
+        cand = np.array(candidates, np.int64)
         if metric in UNCERTAINTY_METRICS:
             return self._sweep.submit(
-                self._params, self._pool(cand), TopKSink(k, metric),
+                self._params, self._pages(cand), TopKSink(k, metric),
                 map_result=lambda rows: cand[rows])
         if metric == "kcenter":
             return self._sweep.submit_call(self.kcenter_candidates, k, cand,
