@@ -33,17 +33,15 @@ LOOK_MARGINS = (0.0, 1.0, 2.0, 4.0)
 def label_look(refs, runs, dtype=None):
     """``label_gap`` over the rows whose reference margin is at least
     each of :data:`LOOK_MARGINS`."""
-    from bench import reference
     worst = [0.0] * len(LOOK_MARGINS)
     for run in runs:
         if not run.committed or not run.machine_mask.any():
             continue
         rows = np.nonzero(run.machine_mask)[0]
         n = run.train_sizes[-1]
-        want, margin = reference.top1_margin(refs.get(run, n),
-                                             refs.x[rows])
-        got = run.labels[rows] if dtype is None else reference.top1_margin(
-            refs.get(run, n, dtype), refs.x[rows], dtype)[0]
+        want, margin = refs.top1_margin(run, n, rows)
+        got = run.labels[rows] if dtype is None else refs.top1_margin(
+            run, n, rows, dtype)[0]
         for i, t in enumerate(LOOK_MARGINS):
             keep = margin >= t
             if keep.any():
@@ -58,7 +56,7 @@ def campaign_look(refs, runs):
     the retrain) and labeled-set size, and the error on the
     machine-labeled rows of the reference's retrain at float32 and at
     bfloat16."""
-    from bench import checks, reference
+    from bench import checks
     out = []
     for run in runs:
         if not run.committed:
@@ -66,9 +64,8 @@ def campaign_look(refs, runs):
         rows = np.nonzero(run.machine_mask)[0]
         n = run.train_sizes[-1]
         truth = refs.y[rows]
-        f32, _ = reference.top1_margin(refs.get(run, n), refs.x[rows])
-        bf16, _ = reference.top1_margin(refs.get(run, n, "bfloat16"),
-                                        refs.x[rows], "bfloat16")
+        f32, _ = refs.top1_margin(run, n, rows)
+        bf16, _ = refs.top1_margin(run, n, rows, "bfloat16")
         losses = np.asarray(run.losses[-1], np.float64)
         last = losses[-max(len(losses) // refs.cell.config["labeler"][
             "epochs"], 1):]

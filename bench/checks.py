@@ -1,9 +1,10 @@
 """The comparisons that decide ``correct``.
 
-Each number compares what the window produced with a plain reference
-(:mod:`bench.reference`) or with the generator's ground truth, and has a
-limit of its own in ``bench/limits/<cell>.json``; a cell compares the
-numbers its limits file names.
+Each number compares what the window produced with the plain reference
+of the cell's labeler (``first_losses``, ``retrain`` and ``top1_margin``
+of its module in ``bench/labelers``) or with the generator's ground
+truth, and has a limit of its own in ``bench/limits/<cell>.json``; a
+cell compares the numbers its limits file names.
 
 * ``fit_step1_loss_gap``: every retrain of every window campaign.  The
   reference retrains from scratch at float32 ``HIGHEST`` on the same
@@ -32,8 +33,6 @@ from typing import Dict, List
 
 import numpy as np
 
-from bench import reference
-
 # a gap that cannot be read at all (a retrain missing, a step missing)
 BROKEN = 1.0e9
 # the reference follows a retrain's first steps; the check compares the
@@ -41,19 +40,11 @@ BROKEN = 1.0e9
 FIT_STEPS = 4
 
 
-def _fit_kw(cell) -> Dict:
-    c, lab = cell.config, cell.config["labeler"]
-    return dict(hidden=lab["hidden"], depth=lab["depth"],
-                classes=c["classes"], batch=lab["batch"],
-                lr=lab["learning_rate"], weight_decay=lab["weight_decay"],
-                steps=FIT_STEPS)
-
-
 def fit_loss_gaps(cell, x, y, runs, dtype=None) -> np.ndarray:
     """Widest relative loss gap at each of the first :data:`FIT_STEPS`
     steps over every retrain of the window: the program's losses, or
     with ``dtype`` the reference's own at that precision (the control)."""
-    kw = _fit_kw(cell)
+    ref_losses = cell.labeler.first_losses
     worst = np.zeros(FIT_STEPS)
     for run in runs:
         if not run.committed:
@@ -62,12 +53,13 @@ def fit_loss_gaps(cell, x, y, runs, dtype=None) -> np.ndarray:
             return np.full(FIT_STEPS, BROKEN)
         for r, n in enumerate(run.train_sizes):
             rows = run.B_idx[:n]
-            ref = reference.first_losses(run.seed, x[rows], y[rows], **kw)
+            ref = ref_losses(cell.config, run.seed, x[rows], y[rows],
+                             FIT_STEPS)
             if dtype is None:
                 got = np.asarray(run.losses[r], np.float64)[:FIT_STEPS]
             else:
-                got = reference.first_losses(run.seed, x[rows], y[rows],
-                                             dtype=dtype, **kw)
+                got = ref_losses(cell.config, run.seed, x[rows], y[rows],
+                                 FIT_STEPS, dtype)
             if len(got) < FIT_STEPS or not np.all(np.isfinite(got)):
                 return np.full(FIT_STEPS, BROKEN)
             worst = np.maximum(worst, np.abs(got - ref) / np.abs(ref))
@@ -86,15 +78,17 @@ class Retrains:
     def get(self, run, n: int, dtype: str = "float32"):
         key = (run.seed, int(n), dtype)
         if key not in self._memo:
-            c, lab = self.cell.config, self.cell.config["labeler"]
             rows = run.B_idx[:n]
-            self._memo[key] = reference.retrain(
-                run.seed, self.x[rows], self.y[rows], hidden=lab["hidden"],
-                depth=lab["depth"], classes=c["classes"],
-                batch=lab["batch"], epochs=lab["epochs"],
-                lr=lab["learning_rate"], weight_decay=lab["weight_decay"],
-                dtype=dtype)
+            self._memo[key] = self.cell.labeler.retrain(
+                self.cell.config, run.seed, self.x[rows], self.y[rows],
+                dtype)
         return self._memo[key]
+
+    def top1_margin(self, run, n: int, rows, dtype: str = "float32"):
+        """The reference's top-1 class and margin of pool ``rows`` under
+        the retrain of ``get(run, n, dtype)``."""
+        return self.cell.labeler.top1_margin(self.get(run, n, dtype),
+                                             self.x[rows], dtype)
 
 
 def _committed(runs) -> List:
@@ -113,12 +107,11 @@ def label_gap(refs: Retrains, runs, dtype=None) -> float:
         if not len(rows):
             continue
         n = run.train_sizes[-1]
-        want, _ = reference.top1_margin(refs.get(run, n), refs.x[rows])
+        want, _ = refs.top1_margin(run, n, rows)
         if dtype is None:
             got = run.labels[rows]
         else:
-            got, _ = reference.top1_margin(refs.get(run, n, dtype),
-                                           refs.x[rows], dtype)
+            got, _ = refs.top1_margin(run, n, rows, dtype)
         worst = max(worst, float(np.mean(got != want)))
     return worst
 

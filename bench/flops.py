@@ -1,24 +1,16 @@
 """Operations and bytes of the benchmark's work, from shapes alone.
 
-The labeler is the ``LiveTask`` MLP: an input projection, ``depth``
-residual ``hidden x hidden`` blocks, an RMS norm and a class head.  Its
-multiply-accumulates per row are the three matmuls; biases, activations
-and the norm are elementwise and not counted.  A retrain counts
-6 x MACs per row per epoch (forward 2, backward 4); scoring counts
-2 x MACs per row.  Only unpadded rows count, so padding that a program
-adds shows as lower utilization, not as work.
+A labeler's multiply-accumulates per row come from its module in
+``bench/labelers`` (``macs_per_row``).  A retrain counts 6 x MACs per
+row per epoch (forward 2, backward 4); scoring counts 2 x MACs per row.
+Only unpadded rows count, so padding that a program adds shows as lower
+utilization, not as work.
 """
 from __future__ import annotations
 
 import json
 import os
 from typing import Dict, Tuple
-
-
-def mlp_macs_per_row(dim: int, hidden: int, depth: int,
-                     classes: int) -> int:
-    """Multiply-accumulates of one labeler forward pass over one row."""
-    return dim * hidden + depth * hidden * hidden + hidden * classes
 
 
 def fit_flops(rows: int, epochs: int, macs_per_row: int) -> float:

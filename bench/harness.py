@@ -3,10 +3,12 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 A cell of ``BENCHMARK.json`` names a configuration (``bench/configs``:
-the pool and the labeler) and a traffic mix (``bench/traffic``: the
+the pool and the labeler, whose ``labeler.arch`` names its module in
+``bench/labelers``) and a traffic mix (``bench/traffic``: the
 acquisition metric, the accuracy target, the labeling service and the
-driver that runs the window, ``bench/drivers``).  Set-up generates the
-pool from ``--seed``, builds one shared engine bundle, warms every fit
+driver that runs the window, ``bench/drivers``).  Set-up fixes the
+host allocator's thresholds (:func:`pin_malloc`), generates the pool
+from ``--seed``, builds one shared engine bundle, warms every fit
 and scoring bucket a campaign on this pool can reach, and runs two
 warm-up campaigns.  The window then runs campaigns back to back, each at
 a campaign seed of its own, until ``--seconds`` have passed; the
@@ -24,10 +26,13 @@ from __future__ import annotations
 
 import argparse
 import contextlib
+import ctypes
+import ctypes.util
 import dataclasses
 import importlib.util
 import json
 import os
+import re
 import shutil
 import statistics
 import sys
@@ -46,7 +51,16 @@ WARM_CAMPAIGNS = 2       # warm-up campaigns in every set-up
 # what the harness runs; a cell whose files ask for anything else is
 # refused rather than run as something it does not say
 SUPPORTED = {("traffic", "mode"): "sync", ("traffic", "annotation"): "oracle",
-             ("labeler", "arch"): "mlp", ("labeler", "dtype"): "float32"}
+             ("labeler", "dtype"): "float32"}
+# what a labeler module, ``bench/labelers/<labeler.arch>.py``, provides
+# (``bench/labelers/mlp.py`` says what each does)
+LABELER = ("pool", "engines", "task", "first_losses", "retrain",
+           "top1_margin", "macs_per_row")
+NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.-]*")   # a file name, no path
+# glibc's malloc thresholds for the run (mallopt(3)): the values that its
+# dynamic rule raises them to after the process frees a 32 MiB block
+M_TRIM_THRESHOLD, M_MMAP_THRESHOLD = -1, -3
+MALLOC = {M_MMAP_THRESHOLD: 32 << 20, M_TRIM_THRESHOLD: 64 << 20}
 
 
 class NoDevice(RuntimeError):
@@ -65,6 +79,7 @@ class Cell:
     limits: Dict
     end_to_end: List[Dict]
     per_layer: List[Dict]
+    labeler: object = None   # the module of ``config["labeler"]["arch"]``
 
 
 def _for_cell(metrics: List[Dict], cell: str) -> List[Dict]:
@@ -96,12 +111,31 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
         if files[part].get(key) != want:
             raise ValueError(f"{name}: {part} {key}={files[part].get(key)!r}"
                              f" is not run by this harness (only {want!r})")
+    cell.labeler = load_labeler(name, cell.config["labeler"].get("arch"),
+                                root)
     return cell
 
 
-def load_module(kind: str, name: str):
-    """``bench/<kind>/<name>.py`` as a module (drivers, metric readers)."""
-    path = os.path.join(BENCH, kind, name + ".py")
+def load_labeler(cell: str, arch, root: str = ROOT):
+    """The module of labeler family ``arch``, or a ``ValueError`` that
+    names the key."""
+    if not (isinstance(arch, str) and NAME.fullmatch(arch)
+            and os.path.isfile(os.path.join(root, "bench", "labelers",
+                                            arch + ".py"))):
+        raise ValueError(f"{cell}: labeler arch={arch!r} has no module "
+                         f"bench/labelers/{arch}.py")
+    mod = load_module("labelers", arch, root)
+    missing = [f for f in LABELER if not callable(getattr(mod, f, None))]
+    if missing:
+        raise ValueError(f"{cell}: labeler arch={arch!r}: "
+                         f"bench/labelers/{arch}.py lacks {missing}")
+    return mod
+
+
+def load_module(kind: str, name: str, root: str = ROOT):
+    """``bench/<kind>/<name>.py`` as a module (drivers, metric readers,
+    labelers)."""
+    path = os.path.join(root, "bench", kind, name + ".py")
     spec = importlib.util.spec_from_file_location(
         f"bench_{kind}_{name.replace('.', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
@@ -136,6 +170,22 @@ def memory_peak(chips: int) -> int:
     return max(peaks)
 
 
+def pin_malloc() -> None:
+    """Fix glibc's mmap and trim thresholds for the rest of the process.
+
+    Left dynamic, they start at 128 KiB and rise the first time the
+    process frees a large mapped block: while compiling, when the profiler
+    stops, or at some point of the window.  Until then every host gather
+    of more than 128 KiB maps fresh pages and faults each one in, and a
+    campaign takes about a third longer, so a run's speed followed whether
+    it compiled.  Fixed at the values the rise ends at, every run starts in
+    the state a long-running process reaches."""
+    libc = ctypes.CDLL(ctypes.util.find_library("c"))
+    for option, value in MALLOC.items():
+        if libc.mallopt(option, value) != 1:
+            raise RuntimeError(f"mallopt({option}, {value}) refused")
+
+
 # -- campaigns -----------------------------------------------------------------
 
 
@@ -157,6 +207,10 @@ class CampaignRun:
     train_sizes: List[int] = dataclasses.field(default_factory=list)
     losses: List = dataclasses.field(default_factory=list)
     spans: Dict[str, float] = dataclasses.field(default_factory=dict)
+    # the registry's counter series, as ``snapshot()["counters"]`` lists
+    # them (name, labels, value); ``repro.obs.export.snapshot_counter``
+    # sums them by name and labels
+    counters: List[Dict] = dataclasses.field(default_factory=list)
 
     @property
     def committed(self) -> bool:
@@ -181,10 +235,10 @@ def _wrap(obj, attr: str, label: str) -> None:
     setattr(obj, attr, wrapped)
 
 
-def span_totals(registry) -> Dict[str, float]:
-    """Seconds per span name recorded by the program's registry."""
+def span_totals(snapshot) -> Dict[str, float]:
+    """Seconds per span name in a snapshot of the program's registry."""
     out: Dict[str, float] = {}
-    for h in registry.snapshot()["histograms"]:
+    for h in snapshot["histograms"]:
         if h["name"] == "span_seconds":
             name = h["labels"].get("name", "")
             out[name] = out.get(name, 0.0) + float(h["sum"])
@@ -196,29 +250,17 @@ class Env:
 
     def __init__(self, cell: Cell, seed: int, trace: bool):
         self.cell, self.trace = cell, trace
-        c, t = cell.config, cell.traffic
         self.set_pool(seed)
         from repro.core import SERVICES
-        from repro.launch.orchestrator import SharedEngines
-        self.service = SERVICES[t["service"]]
-        lab = c["labeler"]
-        self.bundle = SharedEngines.build(
-            c["features"], c["classes"], hidden=lab["hidden"],
-            depth=lab["depth"], epochs=lab["epochs"],
-            batch_size=lab["batch"], learning_rate=lab["learning_rate"],
-            score_microbatch=t["score_microbatch"],
-            sweep_page=t["sweep_page"])
+        self.service = SERVICES[cell.traffic["service"]]
+        self.bundle = cell.labeler.engines(cell.config, cell.traffic)
         self._run: Optional[CampaignRun] = None   # the campaign recording
         self._record()
 
     def set_pool(self, seed: int) -> None:
-        """Generate the pool of ``seed`` (features and ground truth)."""
-        from bench.synth import make_classification
-        c = self.cell.config
+        """Generate the pool of ``seed`` (rows and ground truth)."""
         self.seed = seed
-        self.x, self.y = make_classification(
-            c["pool"], c["classes"], c["features"], c["difficulty"],
-            c["hard_frac"], seed)
+        self.x, self.y = self.cell.labeler.pool(self.cell.config, seed)
 
     def _record(self) -> None:
         """Keep what the window's fits return, for the checks once the
@@ -275,14 +317,12 @@ class Env:
 
     def run_campaign(self, seed: int) -> CampaignRun:
         """One campaign at campaign seed ``seed``, bootstrap to commit, on
-        a fresh ``LiveTask`` over the shared bundle."""
-        from repro.core import LiveTask, MCALCampaign, MCALConfig
+        a fresh task of the labeler over the shared bundle."""
+        from repro.core import MCALCampaign, MCALConfig
         c, t = self.cell.config, self.cell.traffic
         run = CampaignRun(seed=seed)
-        task = LiveTask(features=self.x, groundtruth=self.y,
-                        num_classes=c["classes"], seed=seed,
-                        engines=self.bundle, sweep_page=t["sweep_page"],
-                        score_microbatch=t["score_microbatch"])
+        task = self.cell.labeler.task(c, t, self.x, self.y, seed,
+                                      self.bundle)
         cfg = MCALConfig(eps_target=t["eps"], metric=t["metric"],
                          l_metric=t["l_metric"], seed=seed)
         camp = MCALCampaign(task, self.service, cfg)
@@ -330,7 +370,9 @@ class Env:
             self._run = None
             camp.close()
         if registry is not None:
-            run.spans = span_totals(registry)
+            snapshot = registry.snapshot()
+            run.spans = span_totals(snapshot)
+            run.counters = list(snapshot["counters"])
         return run
 
 
@@ -414,6 +456,7 @@ def main(argv=None, t_start: Optional[float] = None,
     a run on the CPU."""
     t_start = time.perf_counter() if t_start is None else t_start
     args = parse(argv)
+    pin_malloc()
     if not os.path.isdir(os.path.join(ROOT, "src", "repro")):
         print(f"no program under {ROOT}/src/repro", file=sys.stderr)
         return 2

@@ -33,8 +33,7 @@ def campaign_flops(run, cell) -> float:
     over the unlabeled rows before each acquisition, and the commit's
     passes over the test set and the rest of the pool."""
     c, lab = cell.config, cell.config["labeler"]
-    macs = flops.mlp_macs_per_row(c["features"], lab["hidden"], lab["depth"],
-                                  c["classes"])
+    macs = cell.labeler.macs_per_row(c)
     pool, T = c["pool"], len(run.T_idx)
     total = 0.0
     for n in run.train_sizes:

@@ -1,6 +1,7 @@
 """Seconds per campaign in the program's ``gather`` spans: the host
-gathers of feature rows that feed the device (candidates, the commit's
-rows, the test set, the labeled set before each retrain)."""
+gathers of the labeled set before each retrain and of the test set.  The
+sweeps' candidate and commit rows are gathered page by page inside the
+``sweep`` spans and count in ``sweep_s``."""
 from bench.layers import mean_span
 
 

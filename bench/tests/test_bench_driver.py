@@ -57,3 +57,27 @@ def test_traced_window_profiles_only_the_first_campaign():
     runs = driver.run_window(FakeEnv(0.01), harness.window_seeds(1), 0.05,
                              traced=Ctx)
     assert len(runs) >= 2 and entered == [1]
+
+
+def test_pinned_malloc_reuses_freed_host_pages():
+    """After ``pin_malloc`` a freed 16 MiB host array's pages serve the
+    next one, in a fresh process whose malloc thresholds have not risen
+    yet; left dynamic, the second array maps pages of its own and faults
+    each one in."""
+    import subprocess
+    import textwrap
+    code = textwrap.dedent(f"""
+        import resource, sys
+        import numpy as np
+        sys.path.insert(0, {ROOT!r})
+        from bench import harness
+        harness.pin_malloc()
+        a = np.ones(4 << 20, np.float32)   # 16 MiB
+        del a
+        f = resource.getrusage(resource.RUSAGE_SELF).ru_minflt
+        b = np.ones(4 << 20, np.float32)
+        print(resource.getrusage(resource.RUSAGE_SELF).ru_minflt - f)
+    """)
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, check=True, timeout=120)
+    assert int(out.stdout.split()[-1]) < 64, out.stdout

@@ -7,15 +7,21 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)))))
 
-from bench import flops  # noqa: E402
+from bench import flops, harness  # noqa: E402
+
+
+def _mlp_config(dim, hidden, depth, classes):
+    return {"features": dim, "classes": classes,
+            "labeler": {"hidden": hidden, "depth": depth}}
 
 
 def test_mlp_macs_per_row():
+    macs = harness.load_module("labelers", "mlp").macs_per_row
     # 512 -> 512 input projection, two 512 x 512 blocks, 512 -> 10 head
-    assert flops.mlp_macs_per_row(512, 512, 2, 10) == \
+    assert macs(_mlp_config(512, 512, 2, 10)) == \
         512 * 512 + 2 * 512 * 512 + 512 * 10 == 791_552
     # 3 -> 4, one 4 x 4 block, 4 -> 2: 12 + 16 + 8
-    assert flops.mlp_macs_per_row(3, 4, 1, 2) == 36
+    assert macs(_mlp_config(3, 4, 1, 2)) == 36
 
 
 def test_fit_and_score_flops():
